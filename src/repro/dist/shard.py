@@ -1,32 +1,16 @@
-"""Version-compat ``shard_map``.
+"""The repo's one ``shard_map`` entry point.
 
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to the top
-level and renamed ``check_rep`` to ``check_vma`` along the way. Every
-call site in this repo goes through this wrapper so the rest of the code
-is version-agnostic. Replication checking defaults to *off*: the
-custom-VJP collective pairs in :mod:`repro.core.collectives` and the
-transport layer intentionally produce device-varying intermediates that
-older checkers reject.
+Replication checking defaults to *off*: the custom-VJP collective pairs
+in :mod:`repro.core.collectives` and the transport layer intentionally
+produce device-varying intermediates that the checker rejects.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-try:
-    _shard_map = jax.shard_map  # jax >= 0.6
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_PARAMS = set(inspect.signature(_shard_map).parameters)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False, **kw):
-    if "check_vma" in _PARAMS:
-        kw["check_vma"] = check_vma
-    elif "check_rep" in _PARAMS:
-        kw["check_rep"] = check_vma
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=check_vma, **kw,
     )

@@ -1,9 +1,9 @@
 """Pallas TPU kernel: online-softmax flash prefill attention.
 
-The fused form of :func:`repro.models.attention.attend_tiled`: one grid
-cell per ``(batch, head, q-block)`` runs the ``(m, l, acc)`` running
-rescale over k-blocks *inside* the kernel, so the ``(Sq, Sk)`` score
-matrix never round-trips through HBM — scores, softmax weights and the
+The fused form of :func:`repro.models.attention.attend_tiled`: each
+``(batch, head, q-block)`` runs the ``(m, l, acc)`` running rescale over
+its k-blocks *inside* the kernel, so the ``(Sq, Sk)`` score matrix never
+round-trips through HBM — scores, softmax weights and the
 weighted value sum live entirely in VMEM. That is the paper's thesis
 applied to attention itself: the data motion (score traffic) shrinks,
 the FLOPs stay identical.
@@ -19,7 +19,10 @@ reference path keeps using ``attend_tiled`` (the bit-exactness pin vs
 
 GQA layout: ``q (B, H, Sq, hd)`` attends ``k/v (B, Kv, Sk, hd)`` with
 ``G = H // Kv`` query heads sharing each kv head (the k/v BlockSpec
-index map walks ``h // G``).
+index map walks ``h // G``). The grid is ``(B, H, q-block, k-block)``;
+the ``(m, l, acc)`` carry lives in VMEM scratch across the sequential
+k-block steps (initialised at the first, written out at the last), as in
+:mod:`repro.kernels.paged_attention`.
 """
 from __future__ import annotations
 
@@ -28,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bitpack import resolve_interpret
 
@@ -70,27 +74,35 @@ def _tile_mask(q_pos, j, block_q, block_k, causal):
     return q_pos >= k_pos
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *,
-                  block_k: int, seq_k: int, causal: bool, q_offset: int):
+def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                  causal: bool, q_offset: int):
     qi = pl.program_id(2)
-    bq, hd = q_ref.shape[2], q_ref.shape[3]
-    q = q_ref[0, 0]
+    j = pl.program_id(3)
+    bq, bk = q_ref.shape[2], k_ref.shape[2]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
     q_pos = q_offset + qi * bq + jax.lax.broadcasted_iota(
-        jnp.int32, (bq, block_k), 0
+        jnp.int32, (bq, bk), 0
     )
-    m0 = jnp.full((bq,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq,), jnp.float32)
-    a0 = jnp.zeros((bq, hd), jnp.float32)
+    mask = _tile_mask(q_pos, j, bq, bk, causal)
+    m, l, acc = _flash_tile(
+        q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], mask,
+        m_ref[...], l_ref[...], acc_ref[...],
+    )
+    m_ref[...] = m
+    l_ref[...] = l
+    acc_ref[...] = acc
 
-    def body(j, carry):
-        m, l, acc = carry
-        k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k)]
-        v_blk = v_ref[0, 0, pl.ds(j * block_k, block_k)]
-        mask = _tile_mask(q_pos, j, bq, block_k, causal)
-        return _flash_tile(q, k_blk, v_blk, mask, m, l, acc)
-
-    m, l, acc = jax.lax.fori_loop(0, seq_k // block_k, body, (m0, l0, a0))
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _emit():
+        o_ref[0, 0] = (
+            acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        ).astype(o_ref.dtype)
 
 
 def _resolve_blocks(Sq, Sk, block_q, block_k):
@@ -122,9 +134,9 @@ def flash_prefill(
 
     ``q_offset`` is the absolute position of ``q[..., 0, :]`` relative to
     ``k[..., 0, :]`` (prefill continuation), as in ``attend_tiled``. The
-    full k/v sequence of one kv head is staged per grid cell, so the
-    VMEM working set is ``O(Sk * hd)`` — prefill-sized sequences, not
-    training contexts.
+    k-blocks are the innermost grid axis, so K/V stream through VMEM one
+    ``(block_k, hd)`` tile at a time and the working set does not grow
+    with ``Sk``.
     """
     B, H, Sq, hd = q.shape
     Kv, Sk = k.shape[1], k.shape[2]
@@ -133,18 +145,28 @@ def flash_prefill(
     G = H // Kv
     block_q, block_k = _resolve_blocks(Sq, Sk, block_q, block_k)
     return pl.pallas_call(
-        functools.partial(
-            _flash_kernel, block_k=block_k, seq_k=Sk,
-            causal=causal, q_offset=q_offset,
-        ),
-        grid=(B, H, Sq // block_q),
+        functools.partial(_flash_kernel, causal=causal, q_offset=q_offset),
+        grid=(B, H, Sq // block_q, Sk // block_k),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, Sk, hd), lambda b, h, i: (b, h // G, 0, 0)),
-            pl.BlockSpec((1, 1, Sk, hd), lambda b, h, i: (b, h // G, 0, 0)),
+            pl.BlockSpec(
+                (1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)
+            ),
+            pl.BlockSpec(
+                (1, 1, block_k, hd), lambda b, h, i, j: (b, h // G, j, 0)
+            ),
+            pl.BlockSpec(
+                (1, 1, block_k, hd), lambda b, h, i, j: (b, h // G, j, 0)
+            ),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b, h, i: (b, h, i, 0)),
+        out_specs=pl.BlockSpec(
+            (1, 1, block_q, hd), lambda b, h, i, j: (b, h, i, 0)
+        ),
         out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, hd), jnp.float32),
+        ],
         interpret=resolve_interpret(interpret),
     )(q, k, v)
 

@@ -2,8 +2,10 @@
 
 The paper's profile (Tables II/III) shows the AWP l²-norm as the algorithm's
 only measurable cost, so it gets a fused kernel: one pass over the weights,
-accumulating a scalar across sequential grid steps (output block revisited
-every step; initialised on step 0).
+accumulating one ``(8, 128)`` vreg-shaped partial sum across sequential grid
+steps (output block revisited every step; initialised on step 0). The TPU
+compiler stores no scalars to VMEM, so the final 1024-way sum runs outside
+the kernel.
 """
 from __future__ import annotations
 
@@ -16,15 +18,16 @@ from jax.experimental import pallas as pl
 from repro.kernels.bitpack import LANES, resolve_interpret
 
 NORM_BLOCK_ROWS = 512
+SUBLANES = 8
 
 
 def _l2norm_kernel(w_ref, acc_ref):
     @pl.when(pl.program_id(0) == 0)
     def _init():
-        acc_ref[0, 0] = jnp.float32(0.0)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     x = w_ref[...].astype(jnp.float32)
-    acc_ref[0, 0] += jnp.sum(x * x)
+    acc_ref[...] += jnp.sum((x * x).reshape(-1, SUBLANES, LANES), axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
@@ -46,8 +49,8 @@ def l2norm_sq_2d(
         _l2norm_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=pl.BlockSpec((SUBLANES, LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((SUBLANES, LANES), jnp.float32),
         interpret=interpret,
     )(w)
-    return out[0, 0]
+    return jnp.sum(out)

@@ -34,6 +34,7 @@ import numpy as np
 from repro.configs.registry import ARCHS, get_config, reduced
 from repro.dist.spec import build_spec_tree, tree_to_storage
 from repro.fleet import DecodeReplica, FleetRouter, PrefillWorker, WeightPublisher
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_from_cfg
 from repro.launch.train import _null, parse_mesh
 from repro.models.init import init_params
@@ -129,6 +130,7 @@ def main():
                     help="assert router streams bit-exact vs the static "
                          "reference, per weight version (CI smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -7,6 +7,7 @@ XLA host-platform flag, and only in its own process.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from repro.dist.spec import MeshCfg
 
@@ -17,7 +18,7 @@ MULTI_POD = MeshCfg(tp=16, dp=16, pods=2)
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def mesh_cfg_for(*, multi_pod: bool = False) -> MeshCfg:
@@ -28,4 +29,13 @@ def make_mesh_from_cfg(mesh_cfg: MeshCfg):
     """Arbitrary-geometry mesh (tests use small ones, e.g. 2x2x2)."""
     if mesh_cfg.tp == 1 and mesh_cfg.dshards == 1:
         return None
-    return jax.make_mesh(mesh_cfg.shape, mesh_cfg.axis_names)
+    return _auto_mesh(mesh_cfg.shape, mesh_cfg.axis_names)
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``. JAX defaults new meshes
+    to ``Explicit`` axes (sharding in types), which rejects the gathers
+    and scatters the steps run on replicated operands; every step here
+    places its own collectives through ``shard_map`` and leaves the rest
+    to the partitioner."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

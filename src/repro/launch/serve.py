@@ -42,6 +42,7 @@ import numpy as np
 from repro.checkpoint.ckpt import load_plan, load_storage
 from repro.configs.registry import ARCHS, get_config, reduced
 from repro.dist.spec import build_spec_tree, tree_to_storage
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_from_cfg
 from repro.launch.train import _null, parse_mesh
 from repro.models.init import init_params
@@ -232,6 +233,7 @@ def main():
                     help="run both paths and assert bit-exact token "
                          "streams (CI smoke)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -40,6 +40,7 @@ from repro.dist.spec import (
     tree_to_storage,
 )
 from repro.roofline.analysis import train_ingest_bytes
+from repro.launch.cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_from_cfg
 from repro.models.init import init_params
 from repro.optim.sgd import SGDConfig, init_momentum
@@ -82,6 +83,7 @@ def plan_from_args(args, nrt: int, spec_tree, mesh_cfg) -> PrecisionPlan:
         chunks = pick_chunks(
             s_loc, max(mesh_cfg.dshards, 1),
             round_to if schedule == "static" else 1,
+            device_kind=jax.devices()[0].device_kind,
         )
         print(f"--chunks auto -> {chunks} (roofline sweep, s_loc={s_loc})")
     else:
@@ -165,16 +167,22 @@ def main():
                          "are bit-exact on overlapping steps (resume "
                          "determinism) and exit nonzero otherwise")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
     mesh_cfg = parse_mesh(args.mesh)
-    if mesh_cfg.tp * mesh_cfg.dshards > len(jax.devices()):
+    need, devices = mesh_cfg.tp * mesh_cfg.dshards, jax.devices()
+    if need > len(devices):
+        if devices[0].platform == "cpu":
+            hint = "set XLA_FLAGS=--xla_force_host_platform_device_count=N"
+        else:
+            hint = (f"this host has {len(devices)} "
+                    f"{devices[0].device_kind} chips")
         raise SystemExit(
-            f"mesh {args.mesh} needs {mesh_cfg.tp * mesh_cfg.dshards} devices, "
-            f"have {len(jax.devices())} (set XLA_FLAGS=--xla_force_host_"
-            f"platform_device_count=N)"
+            f"mesh {args.mesh} needs {need} devices, have {len(devices)} "
+            f"({hint})"
         )
     mesh = make_mesh_from_cfg(mesh_cfg)
 

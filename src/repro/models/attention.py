@@ -530,11 +530,12 @@ def _paged_write(cache, k, v, page_table):
     )
 
 
-def _flash_prefill_viable(causal, window, is_cross, pos_offset, qg, k):
+def _flash_prefill_viable(mode, causal, window, is_cross, pos_offset, qg, k):
     """The fused flash kernel handles the plain causal prefill shape on a
     real TPU; everything else (CPU tests — the bit-exactness pins — and
-    windows/cross/per-slot offsets/untiled lengths) keeps ``attend_tiled``."""
-    if jax.default_backend() != "tpu":
+    windows/cross/per-slot offsets/untiled lengths) keeps ``attend_tiled``.
+    Training keeps it too: the kernel has no VJP."""
+    if mode != "prefill" or jax.default_backend() != "tpu":
         return False
     if not causal or window is not None or is_cross:
         return False
@@ -734,7 +735,9 @@ def mha(
     else:
         causal = cfg.causal and not is_cross
         q_off = int(pos_offset) if isinstance(pos_offset, int) else 0
-        if _flash_prefill_viable(causal, window, is_cross, pos_offset, qg, k):
+        if _flash_prefill_viable(
+            mode, causal, window, is_cross, pos_offset, qg, k
+        ):
             out = _flash_prefill_call(qg, k, v, q_offset=q_off)
         else:
             out = attend_tiled(

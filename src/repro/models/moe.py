@@ -30,7 +30,7 @@ from repro.utils.trees import round_up
 def _token_split(x, axis_name):
     """fwd: take this rank's token chunk; bwd: all-gather chunk cotangents."""
     m = lax.axis_index(axis_name)
-    tloc = x.shape[0] // axis_size(axis_name)  # version-compat helper
+    tloc = x.shape[0] // axis_size(axis_name)
     return lax.dynamic_slice_in_dim(x, m * tloc, tloc, axis=0)
 
 
@@ -59,7 +59,7 @@ def _tmerge_fwd(x_loc, axis_name):
 
 def _tmerge_bwd(axis_name, _, g):
     m = lax.axis_index(axis_name)
-    tloc = g.shape[0] // axis_size(axis_name)  # version-compat helper
+    tloc = g.shape[0] // axis_size(axis_name)
     return (lax.dynamic_slice_in_dim(g, m * tloc, tloc, axis=0),)
 
 

@@ -6,24 +6,23 @@ shard into independent pack → all-gather → unpack block pipelines so the
 wire time of block *k* overlaps the pack/unpack of block *k±1*. More
 chunks buy more overlap but pay a per-collective launch latency, so
 there is an interior optimum. This helper models the pipeline with the
-same hardware constants as :mod:`repro.roofline.analysis` and returns
-the argmin — the ``plan``-selected chunk count the launchers use for
-``--chunks auto``.
+peak rates of :data:`repro.roofline.analysis.PEAKS` for one device kind
+and returns the argmin — the ``plan``-selected chunk count the launchers
+use for ``--chunks auto``.
 """
 from __future__ import annotations
 
+from repro.roofline.analysis import DRYRUN_KIND, peaks_for
 from repro.transport import CompressionPolicy, policy_for
 
-# TPU v5e-class constants, kept in sync with repro.roofline.analysis
-HBM_BW = 819e9          # bytes/s per chip
-ICI_BW = 50e9           # bytes/s per link
 COLLECTIVE_LATENCY = 5e-6   # s per collective launch (dispatch + sync)
 
 CHUNK_CANDIDATES = (1, 2, 4, 8, 16)
 
 
 def modeled_gather_time(
-    s_loc: int, axis_size: int, policy: CompressionPolicy, chunks: int
+    s_loc: int, axis_size: int, policy: CompressionPolicy, chunks: int,
+    device_kind: str = DRYRUN_KIND,
 ) -> float:
     """Modeled seconds for one chunked compressed all-gather of an
     ``s_loc``-element fp32 shard over ``axis_size`` devices.
@@ -34,12 +33,13 @@ def modeled_gather_time(
     Blocks double-buffer: total ≈ first pack + (chunks-1) overlapped
     stages + last unpack.
     """
+    peaks = peaks_for(device_kind)
     n = max(int(axis_size), 1)
     blk = s_loc / chunks
-    pack_s = blk * (4 + policy.round_to) / HBM_BW
-    unpack_s = n * blk * (policy.round_to + 4) / HBM_BW
+    pack_s = blk * (4 + policy.round_to) / peaks.hbm_bw
+    unpack_s = n * blk * (policy.round_to + 4) / peaks.hbm_bw
     wire_s = (
-        policy.all_gather_wire_bytes(max(int(blk), 1), n) / ICI_BW
+        policy.all_gather_wire_bytes(max(int(blk), 1), n) / peaks.ici_bw
         + COLLECTIVE_LATENCY
     )
     # fill (first pack) + steady state (wire overlaps neighbouring
@@ -54,6 +54,7 @@ def sweep_chunks(
     axis_size: int,
     policy=2,
     candidates=CHUNK_CANDIDATES,
+    device_kind: str = DRYRUN_KIND,
 ) -> dict[int, float]:
     """Modeled gather time per candidate chunk count (only candidates
     that divide ``s_loc`` — the transport falls back to the unchunked
@@ -62,7 +63,9 @@ def sweep_chunks(
     out = {}
     for c in candidates:
         if c >= 1 and s_loc % c == 0:
-            out[c] = modeled_gather_time(s_loc, axis_size, pol, c)
+            out[c] = modeled_gather_time(
+                s_loc, axis_size, pol, c, device_kind
+            )
     return out
 
 
@@ -71,12 +74,13 @@ def pick_chunks(
     axis_size: int,
     policy=2,
     candidates=CHUNK_CANDIDATES,
+    device_kind: str = DRYRUN_KIND,
 ) -> int:
     """The plan-selected chunk count: argmin of :func:`sweep_chunks`
     (1 when nothing divides, or when the gather is degenerate)."""
     if s_loc <= 0 or axis_size <= 1:
         return 1
-    table = sweep_chunks(s_loc, axis_size, policy, candidates)
+    table = sweep_chunks(s_loc, axis_size, policy, candidates, device_kind)
     if not table:
         return 1
     return min(table, key=table.get)

@@ -26,8 +26,9 @@ host<->device token staging (the plan's ``host_device`` traffic class)
 from the same ``CompressionPolicy`` formulas the engine's measured log
 uses, so logged and analytic bytes are pinned equal.
 
-Hardware constants (TPU v5e class, per chip): 197 TFLOP/s bf16,
-819 GB/s HBM, ~50 GB/s/link ICI.
+Peak rates come from :data:`PEAKS`, keyed by ``device_kind`` as JAX
+reports it; a kind missing from the table raises. Compile dry-runs model
+:data:`DRYRUN_KIND`.
 """
 from __future__ import annotations
 
@@ -38,9 +39,34 @@ from typing import Any
 
 from repro.transport import ring_wire_bytes
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link (we charge one link direction)
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peak rates."""
+
+    flops: float   # bf16 FLOP/s
+    hbm_bw: float  # bytes/s
+    ici_bw: float  # bytes/s per link, one direction (what a ring hop pays)
+
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of inter-chip interconnect per chip (4 links x 50 GB/s).
+PEAKS: dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+DRYRUN_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> DevicePeaks:
+    """Peak rates of ``device_kind`` (``jax.Device.device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak rates for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)})"
+        ) from None
+
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -192,8 +218,6 @@ def roofline_from_compiled(
     from repro.roofline.hlo_cost import analyze_hlo, plan_wire_split
 
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0]
     raw_flops = float(cost.get("flops", 0.0))
     raw_bytes = float(cost.get("bytes accessed", 0.0))
     c = analyze_hlo(compiled.as_text())
@@ -213,9 +237,10 @@ def roofline_from_compiled(
         c.wire["reduce-scatter"] -= raw_rs * (1.0 - act_bytes / 4.0)
     flops = max(c.flops, raw_flops)
     hbm = max(c.bytes, raw_bytes)
-    compute_s = flops / PEAK_FLOPS
-    memory_s = hbm / HBM_BW
-    coll_s = c.wire_total / ICI_BW
+    peaks = peaks_for(DRYRUN_KIND)
+    compute_s = flops / peaks.flops
+    memory_s = hbm / peaks.hbm_bw
+    coll_s = c.wire_total / peaks.ici_bw
     terms = {"compute": compute_s, "memory": memory_s, "collective": coll_s}
     dominant = max(terms, key=terms.get)
     useful = model_flops_per_device / flops if flops else 0.0

@@ -70,23 +70,14 @@ AxisNames = Hashable | Sequence[Hashable]
 # ---------------------------------------------------------------------------
 
 
-def _one_axis_size(name) -> int:
-    if hasattr(lax, "axis_size"):  # jax >= 0.5
-        return lax.axis_size(name)
-    import jax.core as jcore  # 0.4.x: axis_frame resolves to the bound size
-
-    frame = jcore.axis_frame(name)
-    return int(getattr(frame, "size", frame))
-
-
 def axis_size(axis_names: AxisNames) -> int:
     """Static total size of one axis name or a tuple of axis names."""
     if isinstance(axis_names, (tuple, list)):
         total = 1
         for a in axis_names:
-            total *= _one_axis_size(a)
+            total *= lax.axis_size(a)
         return total
-    return _one_axis_size(axis_names)
+    return lax.axis_size(axis_names)
 
 
 def resolve_impl(impl: str, mode: str = "truncate") -> str:
@@ -190,15 +181,22 @@ def _packed_reduce_scatter(g, axis_names, round_to, mode, impl, axis: int,
         )
     out_dtype = g.dtype
     gm = jnp.moveaxis(g.astype(jnp.float32), axis, 0)
-    gm = gm.reshape((size, length // size) + gm.shape[1:])
+    block = (length // size,) + gm.shape[1:]
+    gm = gm.reshape((size,) + block)
+    if gm[0].size % LANES == 0:
+        # lay each peer's block out as (rows, 128): the TPU compiler takes
+        # minutes over an all_to_all of u8 planes whose minor dims are
+        # small or ragged (seconds per 10M elements), and under a second
+        # over lane-shaped ones. Same bytes, same order, same values.
+        gm = gm.reshape(size, -1, LANES)
     planes = pack_planes(gm, round_to, mode=mode, impl=impl, key=key)
-    # (round_to, size, loc, ...): exchange the `size` dim; after the
+    # (round_to, size, ...): exchange the `size` dim; after the
     # all_to_all the exchanged dim stays `size` (= one block per peer).
     planes_x = lax.all_to_all(
         planes, axis_names, split_axis=1, concat_axis=1, tiled=False
     )
     contribs = unpack_planes(planes_x, impl=impl)
-    out = jnp.sum(contribs, axis=0)  # fp32 accumulation
+    out = jnp.sum(contribs, axis=0).reshape(block)  # fp32 accumulation
     return jnp.moveaxis(out, 0, axis).astype(out_dtype)
 
 

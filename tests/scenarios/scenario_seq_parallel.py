@@ -198,8 +198,7 @@ def run_ep_moe(mesh_cfg, mesh):
     EP token split (no boundary collective). The psum layout splits the
     flat token axis instead, so per-rank routing sets — and hence
     capacity drops — differ: statistical, not bit, equivalence. Also
-    covers the ep_split path itself (its _token_split/_token_merge used
-    the jax>=0.5-only lax.axis_size and was dead on this pin)."""
+    covers the ep_split path itself (_token_split/_token_merge)."""
     cfg = dataclasses.replace(
         reduced(get_config("mixtral-8x7b")), moe_impl="ep"
     )
