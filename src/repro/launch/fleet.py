@@ -162,7 +162,7 @@ def main():
             DecodeReplica(f"r{i}", ServeEngine(
                 cfg, mesh_cfg, mesh, spec_tree, storage0, plan=plan,
                 max_slots=args.max_slots, cache_capacity=cap, paged=True,
-                page_size=args.page_size,
+                page_size=args.page_size, name=f"r{i}",
             ))
             for i in range(args.replicas)
         ]

@@ -60,6 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.dist.spec import MeshCfg
 from repro.models import model as M
@@ -347,6 +348,8 @@ class ServeEngine:
     :class:`~repro.plan.PrecisionPlan` driving every precision choice
     including the ``host_device`` staging entry. ``cache_capacity`` caps
     ``prompt_len + max_new_tokens`` per request (validated at submit).
+    ``name`` keys the engine's :class:`~repro.obs.Recorder` (``self.obs``)
+    in the process-wide index; engines given one name share it.
     """
 
     def __init__(
@@ -368,6 +371,7 @@ class ServeEngine:
         share_prefix: bool = True,
         draft: DraftBundle | None = None,
         spec_k: int | None = None,
+        name: str = "serve",
     ):
         if not cfg.causal:
             raise ValueError(f"{cfg.name} is encoder-only: nothing to serve")
@@ -450,6 +454,9 @@ class ServeEngine:
         self._page_key: dict[int, tuple] = {}  # page -> interned key
         self._slot_pages: dict[int, list[int]] = {}  # slot -> page row
         self.step_log: list[dict] = []
+        # spans, host gaps, builds and GC pauses, by time (docs/serving.md
+        # §observability); outlives the engine in the index under `name`
+        self.obs = obs.recorder(name)
 
         B = self.max_slots
         self._shard_batch = (
@@ -776,6 +783,7 @@ class ServeEngine:
         self._step = 0
         self._rec = None
         self.step_log = []
+        self.obs.clear()
 
     def _ensure_rec(self) -> dict:
         """The current step's record — admissions accumulate into it,
@@ -873,6 +881,10 @@ class ServeEngine:
         """Local-prefill admission of one request (between decode
         steps). Raises :class:`CapacityError` when :meth:`can_admit`
         says no — callers probe first."""
+        with self.obs.span("serve.admit"):
+            self._admit(req)
+
+    def _admit(self, req: Request) -> None:
         ok, hits = self.can_admit(req)
         if not ok:
             raise CapacityError(
@@ -882,52 +894,64 @@ class ServeEngine:
         rec = self._ensure_rec()
         S, w, page = len(req.prompt_ids), self.token_width, self.page_size
         slot, row = self._alloc_residency(req, hits)
-        planes = pack_tokens_host(
-            np.asarray(req.prompt_ids, np.int32)[None, :], w
-        )  # (w, 1, S) — h2d prompt staging (true length, no pads)
-        rec["host_device"] += planes.nbytes
-        tokens_dev = self._unpack(stage(planes))
-        if self.draft is not None:
-            # draft mirrors the target's residency from the same staged
-            # prompt — one priced h2d crossing covers both prefills
-            self.draft.prefill_insert(tokens_dev, slot)
-        if self.paged:
-            Spad = -(-S // page) * page if self._bucket else S
-            rec["prefill_hits" if Spad in self._prefill_cache
-                else "prefill_misses"] += 1
-            if Spad > S:
-                tokens_dev = jnp.pad(tokens_dev, ((0, 0), (0, Spad - S)))
-            pbatch = {"tokens": tokens_dev,
-                      "last": jnp.asarray(S - 1, jnp.int32)}
-            logits, pcaches = self._prefill(Spad)(self.storage, pbatch)
-            n_hits = len(hits)
-            prompt_pages = -(-S // page)
-            phys = jnp.asarray(row[n_hits:prompt_pages], jnp.int32)
-            self._caches = self._insert_paged(
-                self._caches, pcaches, np.int32(slot), phys,
-                np.int32(n_hits * page), np.int32(S),
-            )
-        else:
-            logits, pcaches = self._prefill(S)(
-                self.storage, {"tokens": tokens_dev}
-            )
-            self._caches = self._insert(self._caches, pcaches, np.int32(slot))
-        s = req.sampling
-        if s.greedy:
-            _, tok_planes = self._sample(logits)  # byte-identical fast path
-        else:
-            _, tok_planes = self._sample_rng(
-                logits,
-                np.asarray([s.temperature], np.float32),
-                np.asarray([s.top_p], np.float32),
-                np.asarray([s.top_k], np.int32),
-                np.asarray([s.seed], np.uint32),
-                np.zeros((1,), np.int32),  # first token = emitted index 0
-            )
-        tok_planes = np.asarray(tok_planes)  # (w, 1) — d2h first id
-        rec["host_device"] += tok_planes.nbytes
-        first = int(unpack_tokens_host(tok_planes)[0])
-        self._finish_admission(req, slot, first, rec)
+        o = self.obs
+        with o.span("serve.stage"):
+            planes = pack_tokens_host(
+                np.asarray(req.prompt_ids, np.int32)[None, :], w
+            )  # (w, 1, S) — h2d prompt staging (true length, no pads)
+            rec["host_device"] += planes.nbytes
+            staged = stage(planes)
+        with o.span("serve.dispatch") as d:
+            o.end_gap(d.start)
+            tokens_dev = self._unpack(staged)
+            if self.draft is not None:
+                # draft mirrors the target's residency from the same
+                # staged prompt — one priced h2d crossing covers both
+                self.draft.prefill_insert(tokens_dev, slot)
+            if self.paged:
+                Spad = -(-S // page) * page if self._bucket else S
+                rec["prefill_hits" if Spad in self._prefill_cache
+                    else "prefill_misses"] += 1
+                if Spad > S:
+                    tokens_dev = jnp.pad(tokens_dev, ((0, 0), (0, Spad - S)))
+                pbatch = {"tokens": tokens_dev,
+                          "last": jnp.asarray(S - 1, jnp.int32)}
+                logits, pcaches = self._prefill(Spad)(self.storage, pbatch)
+                n_hits = len(hits)
+                prompt_pages = -(-S // page)
+                phys = jnp.asarray(row[n_hits:prompt_pages], jnp.int32)
+                self._caches = self._insert_paged(
+                    self._caches, pcaches, np.int32(slot), phys,
+                    np.int32(n_hits * page), np.int32(S),
+                )
+            else:
+                logits, pcaches = self._prefill(S)(
+                    self.storage, {"tokens": tokens_dev}
+                )
+                self._caches = self._insert(
+                    self._caches, pcaches, np.int32(slot)
+                )
+            s = req.sampling
+            if s.greedy:
+                _, tok_planes = self._sample(logits)  # byte-identical path
+            else:
+                _, tok_planes = self._sample_rng(
+                    logits,
+                    np.asarray([s.temperature], np.float32),
+                    np.asarray([s.top_p], np.float32),
+                    np.asarray([s.top_k], np.int32),
+                    np.asarray([s.seed], np.uint32),
+                    np.zeros((1,), np.int32),  # first token = emitted index 0
+                )
+        with o.span("serve.wait") as wt:
+            tok_planes = np.asarray(tok_planes)  # (w, 1) — d2h first id
+        o.gap_from(wt.end)
+        with o.span("serve.emit"):
+            rec["host_device"] += tok_planes.nbytes
+            first = int(unpack_tokens_host(tok_planes)[0])
+            self._finish_admission(req, slot, first, rec)
+        if not self._active:
+            o.gap_from(None)
 
     def admit_pages(self, req: Request, pages, *, n_hits: int,
                     first_tok: int, wire_bytes: int = 0) -> None:
@@ -948,6 +972,11 @@ class ServeEngine:
         if not self.paged:
             raise ValueError("admit_pages needs the paged engine "
                              "(paged=True)")
+        with self.obs.span("serve.admit"):
+            self._admit_pages(req, pages, n_hits, first_tok, wire_bytes)
+
+    def _admit_pages(self, req: Request, pages, n_hits: int,
+                     first_tok: int, wire_bytes: int) -> None:
         ok, hits = self.can_admit(req)
         if not ok:
             raise CapacityError(
@@ -965,22 +994,29 @@ class ServeEngine:
         S, page = len(req.prompt_ids), self.page_size
         slot, row = self._alloc_residency(req, hits)
         prompt_pages = -(-S // page)
-        phys = jnp.asarray(row[len(hits):prompt_pages], jnp.int32)
-        staged = jax.tree_util.tree_map(stage, pages)
-        rec["kv_migration"] += int(wire_bytes)
-        self._caches = self._install_pages(
-            self._caches, staged, np.int32(slot), phys, np.int32(S)
-        )
-        if self.draft is not None:
-            # migration ships target KV, not tokens: the draft must
-            # prefill locally, so the prompt crosses h2d here (priced)
-            dplanes = pack_tokens_host(
-                np.asarray(req.prompt_ids, np.int32)[None, :],
-                self.token_width,
+        o = self.obs
+        with o.span("serve.stage"):
+            phys = jnp.asarray(row[len(hits):prompt_pages], jnp.int32)
+            staged = jax.tree_util.tree_map(stage, pages)
+            rec["kv_migration"] += int(wire_bytes)
+            dstaged = None
+            if self.draft is not None:
+                # migration ships target KV, not tokens: the draft must
+                # prefill locally, so the prompt crosses h2d here (priced)
+                dplanes = pack_tokens_host(
+                    np.asarray(req.prompt_ids, np.int32)[None, :],
+                    self.token_width,
+                )
+                rec["host_device"] += dplanes.nbytes
+                dstaged = stage(dplanes)
+        with o.span("serve.dispatch"):
+            self._caches = self._install_pages(
+                self._caches, staged, np.int32(slot), phys, np.int32(S)
             )
-            rec["host_device"] += dplanes.nbytes
-            self.draft.prefill_insert(self._unpack(stage(dplanes)), slot)
-        self._finish_admission(req, slot, int(first_tok), rec)
+            if dstaged is not None:
+                self.draft.prefill_insert(self._unpack(dstaged), slot)
+        with o.span("serve.emit"):
+            self._finish_admission(req, slot, int(first_tok), rec)
 
     def decode_tick(self) -> None:
         """One engine step: run one batched decode when any slot is
@@ -988,22 +1024,37 @@ class ServeEngine:
         zero-decode record, exactly like the drain loop)."""
         rec = self._ensure_rec()
         rec["active"] = len(self._active)
-        if self._active and self.draft is not None:
-            self._spec_tick(rec)
-        elif self._active:
-            w = self.token_width
+        if self._active:
+            with self.obs.span("serve.tick"):
+                if self.draft is not None:
+                    self._spec_tick(rec)
+                else:
+                    self._tick(rec)
+            if not self._active:
+                self.obs.gap_from(None)
+        self.step_log.append(rec)
+        self._step += 1
+        self._rec = None
+
+    def _tick(self, rec: dict) -> None:
+        """One batched decode over every slot (ballast rows included)."""
+        w, o = self.token_width, self.obs
+        with o.span("serve.stage"):
             feed_planes = pack_tokens_host(
                 self._next_tok[:, None], w
             )  # (w, B, 1)
             rec["host_device"] += feed_planes.nbytes  # h2d token staging
-            tokens_dev = self._unpack(stage(feed_planes))
-            batch = {"tokens": tokens_dev, "pos": stage(self._pos_host)}
+            staged = {"tokens": stage(feed_planes),
+                      "pos": stage(self._pos_host)}
             if self.paged:
                 # the page table is scheduler state staged fresh each step
                 # (retires/admissions edit the host copy between steps)
                 rec["host_device"] += self._table.nbytes
                 rec["page_table"] += self._table.nbytes
-                batch["page_table"] = stage(self._table)
+                staged["page_table"] = stage(self._table)
+        with o.span("serve.dispatch") as d:
+            o.end_gap(d.start)
+            batch = dict(staged, tokens=self._unpack(staged["tokens"]))
             logits, self._caches = self._decode(
                 self._weights, self._caches, batch
             )
@@ -1015,7 +1066,10 @@ class ServeEngine:
                 )
             else:
                 _, out_planes = self._sample(logits)  # byte-identical path
+        with o.span("serve.wait") as wt:
             out_planes = np.asarray(out_planes)  # (w, B) — d2h sampled ids
+        o.gap_from(wt.end)
+        with o.span("serve.emit"):
             rec["host_device"] += out_planes.nbytes
             sampled = unpack_tokens_host(out_planes)
             self._pos_host += 1  # mirrors cache.pos + 1 (ballast too)
@@ -1027,9 +1081,6 @@ class ServeEngine:
                 if st.emit(tok):
                     self._results[st.req.rid] = self._retire(st, self._step)
                     del self._active[slot]
-        self.step_log.append(rec)
-        self._step += 1
-        self._rec = None
 
     def _spec_tick(self, rec: dict) -> None:
         """One speculative round: draft proposes ``spec_k`` ids per slot,
@@ -1048,29 +1099,44 @@ class ServeEngine:
         round). Ballast slots skip rollback entirely — their writes land
         in trash (clamped pages / dropped scatters) or are masked.
         """
-        w, k = self.token_width, self.spec_k
+        w, k, o = self.token_width, self.spec_k, self.obs
         T = k + 1
-        drafts = self.draft.propose(
-            self._next_tok, self._pos_host, self._nemit,
-            self._temp, self._top_p, self._top_k, self._seed, rec,
-        )  # (B, k) host int32
-        feed = np.concatenate([self._next_tok[:, None], drafts], axis=1)
-        feed_planes = pack_tokens_host(feed, w)  # (w, B, T)
-        rec["host_device"] += feed_planes.nbytes  # h2d verify block
-        tokens_dev = self._unpack(stage(feed_planes))
-        batch = {"tokens": tokens_dev, "pos": stage(self._pos_host)}
-        if self.paged:
-            rec["host_device"] += self._table.nbytes
-            rec["page_table"] += self._table.nbytes
-            batch["page_table"] = stage(self._table)
-        logits, self._caches = self._verify(
-            self._weights, self._caches, batch
-        )
-        _, t_planes = self._verify_sample(
-            logits, self._temp, self._top_p, self._top_k,
-            self._seed, self._nemit,
-        )
-        t_planes = np.asarray(t_planes)  # (w, B, T) — d2h verified ids
+        with o.span("serve.draft") as d:
+            # the draft's first program ends the host gap
+            o.end_gap(d.start)
+            drafts = self.draft.propose(
+                self._next_tok, self._pos_host, self._nemit,
+                self._temp, self._top_p, self._top_k, self._seed, rec,
+            )  # (B, k) host int32
+        with o.span("serve.stage"):
+            feed = np.concatenate([self._next_tok[:, None], drafts], axis=1)
+            feed_planes = pack_tokens_host(feed, w)  # (w, B, T)
+            rec["host_device"] += feed_planes.nbytes  # h2d verify block
+            staged = {"tokens": stage(feed_planes),
+                      "pos": stage(self._pos_host)}
+            if self.paged:
+                rec["host_device"] += self._table.nbytes
+                rec["page_table"] += self._table.nbytes
+                staged["page_table"] = stage(self._table)
+        with o.span("serve.dispatch"):
+            batch = dict(staged, tokens=self._unpack(staged["tokens"]))
+            logits, self._caches = self._verify(
+                self._weights, self._caches, batch
+            )
+            _, t_planes = self._verify_sample(
+                logits, self._temp, self._top_p, self._top_k,
+                self._seed, self._nemit,
+            )
+        with o.span("serve.wait") as wt:
+            t_planes = np.asarray(t_planes)  # (w, B, T) — d2h verified ids
+        o.gap_from(wt.end)
+        with o.span("serve.emit"):
+            self._spec_emit(rec, t_planes, drafts)
+
+    def _spec_emit(self, rec: dict, t_planes, drafts) -> None:
+        """Accept rule, emission and rollback of one speculative round."""
+        k = self.spec_k
+        T = k + 1
         rec["host_device"] += t_planes.nbytes
         targets = unpack_tokens_host(t_planes)  # (B, T)
         self._pos_host += T  # mirrors the jitted pos += T (ballast too)
