@@ -5,14 +5,22 @@ pool ``(P, page, Kv, hd)`` and each slot owns a row of the page table
 ``(B, n_pages)`` mapping logical page ``j`` to a physical pool row. The
 kernel walks the table with a scalar-prefetch index map — the grid is
 ``(B, n_pages)`` and the K/V BlockSpec picks block ``table[b, j]`` —
-so only the pages a slot actually owns move from HBM to VMEM. That is
-the data-motion win: resident bytes and gathered bytes scale with the
-tokens written, not with ``max_slots * capacity``.
+and each slot walks only its live prefix, the ``ceil(lengths[b] /
+page)`` pages that hold its tokens. A grid step past that prefix does no
+compute, and its index map repeats the last live page's block index, so
+the pipeline fetches nothing for it either: pages reserved for tokens
+not yet written, and a retired slot's row (``lengths[b] == 0``), cost a
+grid step's fixed overhead and no HBM traffic.
 
 The online softmax ``(m, l, acc)`` carry persists in VMEM scratch
 across the sequential ``j`` steps of one batch row (initialised at
 ``j == 0``, output written at the last ``j``), the same running-rescale
-algebra as :mod:`repro.kernels.flash_prefill`.
+algebra as :mod:`repro.kernels.flash_prefill`. Skipping the dead pages
+changes no bit of a row with ``lengths[b] >= 1``: its first page holds a
+live token, so ``m`` is finite after it, and a fully masked page then
+gives ``m_new = m``, ``p = 0`` and ``corr = 1``, leaving ``l`` and
+``acc`` unchanged to the bit. A ``lengths[b] == 0`` row emits zeros
+(``0 / max(0, 1e-30)``).
 
 Bit-compatibility contract: :func:`paged_attend_ref` replays the exact
 page walk through the shared :func:`_page_update` helper, so kernel and
@@ -78,14 +86,16 @@ def _paged_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    valid = _page_valid(j, page, len_ref[b])
-    m, l, acc = _page_update(
-        q_ref[0], k_ref[0], v_ref[0], valid,
-        m_ref[...], l_ref[...], acc_ref[...],
-    )
-    m_ref[...] = m
-    l_ref[...] = l
-    acc_ref[...] = acc
+    @pl.when(j * page < len_ref[b])  # page j holds a live token
+    def _step():
+        valid = _page_valid(j, page, len_ref[b])
+        m, l, acc = _page_update(
+            q_ref[0], k_ref[0], v_ref[0], valid,
+            m_ref[...], l_ref[...], acc_ref[...],
+        )
+        m_ref[...] = m
+        l_ref[...] = l
+        acc_ref[...] = acc
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _emit():
@@ -107,25 +117,28 @@ def paged_attend(
     """Paged decode attention; returns ``(B, Kv, G, hd)``.
 
     Every table entry must be a valid pool row (point unused entries at
-    a ballast page); rows past ``lengths[b]`` are masked to ``NEG_INF``
-    so their softmax weight is exactly 0.0.
+    a ballast page). Slot ``b`` walks pages ``0 .. ceil(lengths[b] /
+    page) - 1`` only; rows past ``lengths[b]`` within its last page are
+    masked to ``NEG_INF`` so their softmax weight is exactly 0.0, and a
+    ``lengths[b] == 0`` row returns zeros.
     """
     B, Kv, G, hd = q.shape
     P, page = k_pool.shape[0], k_pool.shape[1]
     n_pages = page_table.shape[1]
+
+    def kv_block(b, j, table, lens):
+        # past the live prefix, repeat the last live block: no new DMA
+        # (lens - 1 clamped at 0 first, so nothing negative is divided)
+        last = jnp.maximum(lens[b] - 1, 0) // page
+        return (table[b, jnp.minimum(j, last)], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, n_pages),
         in_specs=[
             pl.BlockSpec((1, Kv, G, hd), lambda b, j, table, lens: (b, 0, 0, 0)),
-            pl.BlockSpec(
-                (1, page, Kv, hd),
-                lambda b, j, table, lens: (table[b, j], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, page, Kv, hd),
-                lambda b, j, table, lens: (table[b, j], 0, 0, 0),
-            ),
+            pl.BlockSpec((1, page, Kv, hd), kv_block),
+            pl.BlockSpec((1, page, Kv, hd), kv_block),
         ],
         out_specs=pl.BlockSpec(
             (1, Kv, G, hd), lambda b, j, table, lens: (b, 0, 0, 0)
@@ -154,7 +167,8 @@ def paged_attend_ref(
     lengths: jnp.ndarray,
 ) -> jnp.ndarray:
     """Pure-JAX oracle: replays the kernel's page walk through the shared
-    :func:`_page_update` helper (bitwise-parity reference).
+    :func:`_page_update` helper (bitwise-parity reference), over the same
+    live prefix of ``ceil(lengths[b] / page)`` pages.
 
     As in ``flash_prefill_ref``, the walk is a jitted ``fori_loop`` with
     ``dynamic_slice`` page gathers so XLA compiles the per-page einsums
@@ -183,7 +197,8 @@ def paged_attend_ref(
             valid = _page_valid(j, page, lengths[b])
             return _page_update(q_b, k_pg, v_pg, valid, m, l, acc)
 
-        m, l, acc = jax.lax.fori_loop(0, n_pages, body, (m0, l0, a0))
+        n_live = jnp.minimum((lengths[b] + page - 1) // page, n_pages)
+        m, l, acc = jax.lax.fori_loop(0, n_live, body, (m0, l0, a0))
         out.append(
             (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
         )

@@ -444,7 +444,9 @@ def attend_decode_paged(
 
     ``impl=None`` dispatches like ``kernels.bitpack.resolve_interpret``:
     the fused page-walking Pallas kernel on a real TPU (fp caches, no
-    window), the dense reference elsewhere. ``impl="dense"`` gathers the
+    window), the dense reference elsewhere; the kernel walks each slot's
+    live prefix only, and a retired slot (its first table entry the trash
+    page) reaches it with length 0. ``impl="dense"`` gathers the
     slot's pages into a contiguous per-slot view and runs the *exact*
     ``attend_decode`` ops — positions past ``pos`` mask to ``NEG_INF``
     so their softmax weight is exactly 0.0, which keeps paged streams
@@ -460,7 +462,12 @@ def attend_decode_paged(
     if impl == "pallas":
         from repro.kernels.paged_attention import paged_attend
 
-        out = paged_attend(q[:, 0], cache.k, cache.v, page_table, cache.pos)
+        # a retired slot's row is all trash (the pool's last row) while
+        # its pos runs on; a live slot always owns its first page, so
+        # length 0 makes the kernel skip exactly the retired rows
+        trash = cache.num_pages - 1
+        lengths = jnp.where(page_table[:, 0] == trash, 0, cache.pos)
+        out = paged_attend(q[:, 0], cache.k, cache.v, page_table, lengths)
         return out[:, None]
     B = q.shape[0]
     n_pages = page_table.shape[1]

@@ -1051,6 +1051,7 @@ class ServeEngine:
                 # (retires/admissions edit the host copy between steps)
                 rec["host_device"] += self._table.nbytes
                 rec["page_table"] += self._table.nbytes
+                rec["attend_pages"] = self._attend_pages()
                 staged["page_table"] = stage(self._table)
         with o.span("serve.dispatch") as d:
             o.end_gap(d.start)
@@ -1081,6 +1082,15 @@ class ServeEngine:
                 if st.emit(tok):
                     self._results[st.req.rid] = self._retire(st, self._step)
                     del self._active[slot]
+
+    def _attend_pages(self) -> int:
+        """Table entries the paged decode kernel walks this tick: each
+        slot that owns its first page walks ``ceil((pos + 1) / page)``
+        of them, at most the table's width; a retired or empty slot's row
+        (all trash) reaches the kernel with length 0 and walks none."""
+        owning = self._table[:, 0] != self.num_pages
+        live = -(-(self._pos_host + 1) // self.page_size)
+        return int(np.minimum(live, self._table_width)[owning].sum())
 
     def _spec_tick(self, rec: dict) -> None:
         """One speculative round: draft proposes ``spec_k`` ids per slot,
@@ -1263,6 +1273,13 @@ class ServeEngine:
                 r.get("page_table", 0) for r in self.step_log
             )
             out["page_table_entries"] = self.max_slots * self._table_width
+            walks = [r["attend_pages"] for r in self.step_log
+                     if "attend_pages" in r]
+            if walks:
+                # the kernel's walk as a share of every slot's whole row
+                out["attend_pages"] = sum(walks)
+                out["attend_walked_share"] = sum(walks) / (
+                    len(walks) * out["page_table_entries"])
             out["prefill_hits"] = sum(
                 r.get("prefill_hits", 0) for r in self.step_log
             )

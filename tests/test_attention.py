@@ -6,7 +6,9 @@ import pytest
 
 from repro.models.attention import (
     KVCache,
+    PagedKVCache,
     attend_decode,
+    attend_decode_paged,
     attend_tiled,
     init_cache,
 )
@@ -160,3 +162,19 @@ def test_int8_prefill_decode_close_to_fp():
     kq, sc = _quantize_kv(k)
     deq = np.asarray(kq, np.float32) * np.asarray(sc)[..., None]
     assert np.max(np.abs(deq - np.asarray(k))) <= np.max(np.asarray(sc)) * 0.51
+
+
+def test_paged_decode_kernel_matches_dense_and_skips_retired_rows():
+    # two live slots with pages reserved past their tokens, and a
+    # retired one: its row all trash (the pool's last row), its pos run
+    # far past the table, as the engine's ballast slots leave them
+    page, hd = 8, 128
+    k_pool = _rand((10, page, 2, hd), 1)
+    v_pool = _rand((10, page, 2, hd), 2)
+    table = jnp.asarray([[3, 0, 5, 1], [2, 7, 4, 8], [9, 9, 9, 9]], jnp.int32)
+    cache = PagedKVCache(k_pool, v_pool, jnp.asarray([11, 25, 1000]))
+    q = _rand((3, 1, 2, 2, hd), 3)
+    got = np.asarray(attend_decode_paged(q, cache, table, impl="pallas"))
+    want = np.asarray(attend_decode_paged(q, cache, table, impl="dense"))
+    np.testing.assert_allclose(got[:2], want[:2], atol=2e-5)
+    np.testing.assert_array_equal(got[2], np.zeros_like(got[2]))

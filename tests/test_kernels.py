@@ -272,3 +272,46 @@ def test_paged_attend_page_table_permutation_invariance():
         paged_attend(q, kp2, vp2, table2, lengths, interpret=True)
     )
     np.testing.assert_array_equal(base, moved)
+
+
+def _reserved_tail_setup(fill, seed=29):
+    # slot rows own distinct real pages past their live prefix (reserved
+    # at admission, never written), filled with ``fill``; the same rows
+    # again with every tail entry on one trash page (the pool's last row)
+    B, Kv, G, page, n_pages = 3, 2, 2, 8, 5
+    q, kp, vp, table, _ = _paged_setup(B, Kv, G, page, n_pages, 16, seed=seed)
+    lengths = np.array([1, 2 * page + 3, 3 * page], np.int32)
+    table = np.asarray(table)
+    live = -(-lengths // page)
+    tail = np.arange(n_pages)[None, :] >= live[:, None]
+    # row 16, in no slot's table, is the trash page
+    kp, vp = (np.concatenate([x, np.zeros_like(x[:1])]) for x in (kp, vp))
+    kp[table[tail]] = fill
+    vp[table[tail]] = fill
+    trash_table = np.where(tail, kp.shape[0] - 1, table).astype(np.int32)
+    return (q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table),
+            jnp.asarray(trash_table), jnp.asarray(lengths))
+
+
+@pytest.mark.parametrize("fill", [1e6, np.nan])
+def test_paged_attend_reserved_tail_bitwise(fill):
+    # the walk stops at each row's live prefix: pages past it are never
+    # computed on, so what they hold (a NaN would poison any product
+    # with a zero weight) cannot reach the output
+    q, kp, vp, table, trash_table, lengths = _reserved_tail_setup(fill)
+    got = np.asarray(paged_attend(q, kp, vp, table, lengths, interpret=True))
+    want = np.asarray(paged_attend_ref(q, kp, vp, table, lengths))
+    np.testing.assert_array_equal(got, want)
+    assert np.all(np.isfinite(got))
+    on_trash = paged_attend(q, kp, vp, trash_table, lengths, interpret=True)
+    np.testing.assert_array_equal(got, np.asarray(on_trash))
+
+
+def test_paged_attend_zero_length_row_is_zero():
+    q, kp, vp, table, _, lengths = _reserved_tail_setup(1e6)
+    lengths = lengths.at[1].set(0)
+    for out in (paged_attend(q, kp, vp, table, lengths, interpret=True),
+                paged_attend_ref(q, kp, vp, table, lengths)):
+        out = np.asarray(out)
+        np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
+        assert np.all(out[[0, 2]] != 0)

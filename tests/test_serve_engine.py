@@ -503,6 +503,37 @@ def test_paged_wire_log_pins_analytic_serve_model(setup):
     assert measured["page_table"] == analytic["page_table_h2d"]
 
 
+def test_paged_attend_pages_counts_live_prefixes(setup):
+    # 5 requests through 2 slots: retirements, slot reuse and, at the
+    # end, a retired slot decoding as ballast beside a live one. A
+    # request admitted at step a with an S-token prompt decodes in
+    # steps a .. finished_step, at pos S + (t - a) before step t, so the
+    # kernel walks ceil((pos + 1) / page) of its entries; retired and
+    # empty slots walk none
+    cfg = setup[0]
+    reqs = _requests(cfg)
+    eng = _paged_engine(setup)
+    results = eng.run(reqs)
+    ticks = [r for r in eng.step_log if r["decoded"]]
+    assert [r["step"] for r in eng.step_log if "attend_pages" in r] == [
+        r["step"] for r in ticks]
+    for rec in ticks:
+        t = rec["step"]
+        want = sum(
+            -(-(len(r.prompt_ids) + t - res.admitted_step + 1) // PAGE)
+            for r in reqs
+            for res in [results[r.rid]]
+            if res.admitted_step <= t <= res.finished_step
+        )
+        assert rec["attend_pages"] == want, t
+    w = eng.wire_summary()
+    walked = sum(r["attend_pages"] for r in ticks)
+    assert w["attend_pages"] == walked
+    assert w["attend_walked_share"] == walked / (
+        len(ticks) * SLOTS * -(-CAPACITY // PAGE))
+    assert 0 < w["attend_walked_share"] < 1
+
+
 def test_paged_rejects_windows_and_oversized_requests(setup):
     cfg, mesh_cfg, spec_tree, storage, plan = setup
     with pytest.raises(ValueError, match="contiguous"):
