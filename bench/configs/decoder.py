@@ -25,6 +25,10 @@ ChatGLM3 ``dense_h_to_4h`` matrix is stored here as its two halves,
 Weights the configuration's serving format stores as byte planes are
 truncated to their leading bytes, as that format defines
 (``weight_planes``), before use.
+
+The counts the metric readers use (:func:`matmul_params`,
+:func:`plane_elements`, :func:`attention_layers`) are this
+architecture's, so they live here beside its forward.
 """
 from __future__ import annotations
 
@@ -69,6 +73,42 @@ def weight_shapes(s: dict) -> dict:
     return shapes
 
 
+def layer_matrices(s: dict) -> dict:
+    """Elements of each per-layer weight matrix."""
+    d, ff = s["d_model"], s["d_ff"]
+    H, K, hd = s["heads"], s["kv_heads"], s["head_dim"]
+    return {"wq": d * H * hd, "wk": d * K * hd, "wv": d * K * hd,
+            "wo": H * hd * d, "w_gate": d * ff, "w_up": d * ff,
+            "w_down": ff * d}
+
+
+def matmul_params(s: dict) -> int:
+    """Parameters a token multiplies through: every layer's matrices
+    and the output head; the embedding is a lookup and does not count."""
+    return s["layers"] * sum(layer_matrices(s).values()) + s["d_model"] * s["vocab"]
+
+
+def plane_elements(s: dict, leaves, min_elements: int,
+                   matrices=layer_matrices) -> int:
+    """Elements of the leaves the serving format keeps as byte planes
+    (each is unpacked once per program call): the listed leaves with at
+    least ``min_elements`` elements per layer, as :func:`as_served`
+    picks them. ``matrices(s)`` gives the per-layer leaves' elements; a
+    reference of another channel mixer passes its own."""
+    per = matrices(s)
+    n = sum(s["layers"] * per[k] for k in leaves
+            if k in per and per[k] >= min_elements)
+    table = s["d_model"] * s["vocab"]
+    n += sum(table for k in ("embed", "head")
+             if k in leaves and table >= min_elements)
+    return n
+
+
+def attention_layers(s: dict) -> int:
+    """Layers that run the paged and flash attention kernels: all."""
+    return s["layers"]
+
+
 def _init(name: str, key, shape):
     """Random weights whose activations stay of order one: matrices
     N(0, 1/fan_in), the embedding N(0, 1), norm scales 1 + N(0, 0.1^2),
@@ -103,14 +143,16 @@ def truncate_planes(x, keep_bytes: int):
     return lax.bitcast_convert_type(u, jnp.float32)
 
 
-def as_served(w: dict, planes: dict, keep_bytes: int, rounding: str) -> dict:
+def as_served(w: dict, planes: dict, keep_bytes: int, rounding: str,
+              layer_keys=LAYER_KEYS) -> dict:
     """The weights as the serving format delivers them to the compute.
     ``planes`` (a configuration's ``weight_planes``) names the leaves the
     format stores as byte planes, and the fewest elements per layer a
-    leaf must have to be stored so; ``keep_bytes`` planes are kept."""
+    leaf must have to be stored so; ``keep_bytes`` planes are kept.
+    ``layer_keys`` are the leaves stacked by layer."""
     if rounding != "truncate":
         raise ValueError(f"unknown plane rounding {rounding!r}")
-    stacked = set(LAYER_KEYS)
+    stacked = set(layer_keys)
 
     def planed(k, v):
         n = int(np.prod(v.shape[1:] if k in stacked else v.shape))
@@ -173,7 +215,10 @@ def _attention(q, k, v, block: int):
 class Reference:
     """The reference forward of one configuration. ``block`` is the
     query-row block of attention; ``chunk`` the served positions whose
-    logits are formed at once."""
+    logits are formed at once. A reference of another channel mixer
+    overrides ``layer_keys`` and :meth:`mix`."""
+
+    layer_keys = LAYER_KEYS
 
     def __init__(self, conf: dict, *, block: int = 512, chunk: int = 256):
         self.conf = conf
@@ -189,7 +234,7 @@ class Reference:
         T = tokens.shape[0]
         H, K, hd = s["heads"], s["kv_heads"], s["head_dim"]
         x = w["embed"][tokens]
-        layers = {k: w[k] for k in LAYER_KEYS if k in w}
+        layers = {k: w[k] for k in self.layer_keys if k in w}
 
         def layer(x, lw):
             h = _rms(x, lw["ln1"], eps)
@@ -203,12 +248,15 @@ class Reference:
             q, k = _rope(q, s, theta), _rope(k, s, theta)
             a = _attention(q, k, v, self.block).reshape(T, H * hd)
             x = x + _mm(a, lw["wo"])
-            h = _rms(x, lw["ln2"], eps)
-            m = jax.nn.silu(_mm(h, lw["w_gate"])) * _mm(h, lw["w_up"])
-            return x + _mm(m, lw["w_down"]), None
+            return x + self.mix(lw, _rms(x, lw["ln2"], eps)), None
 
         x, _ = lax.scan(layer, x, layers)
         return _rms(x, w["final_norm"], eps)
+
+    def mix(self, lw: dict, h):
+        """One layer's channel mixer on its normed input h (T, d)."""
+        m = jax.nn.silu(_mm(h, lw["w_gate"])) * _mm(h, lw["w_up"])
+        return _mm(m, lw["w_down"])
 
     def served_gaps(self, w: dict, prompt, served, *, pad_to: int):
         """(gaps, margins) of every served token. The reference reads

@@ -4,8 +4,10 @@
 ``take_completed``) from one host thread.
 
 Set-up makes the weights from the seed on the device in one jitted
-call, builds the engine with the mix's plan, slots and pages, and warms
-up every program the mix will use (one prefill per prompt bucket, the
+call (the configuration's reference makes them in the published
+layout, its converter turns them into the program's tree), builds the
+engine with the mix's plan, slots and pages, and warms up every
+program the mix will use (one prefill per prompt bucket, the
 page insert of each bucket, the decode step and the samplers). Then the
 window runs for ``--seconds``:
 
@@ -37,6 +39,7 @@ import dataclasses
 import functools
 import gc
 import os
+import pathlib
 import shutil
 import sys
 import time
@@ -46,86 +49,36 @@ import numpy as np
 from bench.harness import registry, trace as tracemod, traffic
 from bench.harness.cell import Outcome
 
-# the program applies rotary embedding to interleaved pairs (2i, 2i+1)
-PROGRAM_ROPE = "interleaved"
 TRACE_DIR = ".bench_trace"
 SPAN_NAMES = {"admit", "decode_tick", "gen_wait", tracemod.WINDOW}
 
 
 @functools.lru_cache(maxsize=None)
-def _module(name: str):
-    return registry.module("configs", name)
+def _module(bench_dir: pathlib.Path, name: str):
+    return registry.module("configs", name, bench_dir=bench_dir)
 
 
-def reference_module(conf: dict):
+def reference_module(ctx):
     """The configuration's plain reference (``bench/configs/<name>.py``)."""
-    return _module(conf["reference"]["module"])
+    return _module(ctx.bench_dir, ctx.conf["reference"]["module"])
 
 
-def program_config(conf: dict):
-    """The program's ModelConfig: the registry entry with every field
-    the configuration file maps set from its published key."""
-    from repro.configs.registry import get_config
-
-    p = conf["program"]
-    cfg = dataclasses.replace(
-        get_config(p["arch"]), **{f: conf[k] for f, k in p["fields"].items()}
-    )
-    s = reference_module(conf).sizes(conf)
-    rot = int(cfg.head_dim * cfg.rotary_pct)
-    if (cfg.qk_norm, cfg.qkv_bias, rot - rot % 2) != (
-            s["qk_norm"], s["qkv_bias"], s["rotary_dim"]):
-        raise ValueError(f"{conf['name']}: the program's qk_norm/qkv_bias/"
-                         "rotary dims differ from the configuration file's")
-    return cfg
+def converter(ctx):
+    """The configuration's weight conversion into the program's tree
+    (``bench/configs/<program.convert>.py``)."""
+    return _module(ctx.bench_dir, ctx.conf["program"]["convert"])
 
 
-def rope_permutation(s: dict) -> np.ndarray:
-    """Program head dim j holds reference head dim perm[j]."""
-    hd, r = s["head_dim"], s["rotary_dim"]
-    perm = np.arange(hd)
-    if s["rope_pairs"] == "half" and PROGRAM_ROPE == "interleaved":
-        i = np.arange(r // 2)
-        perm[0:r:2] = i
-        perm[1:r:2] = i + r // 2
-    elif s["rope_pairs"] != PROGRAM_ROPE:
-        raise ValueError(f"no mapping from {s['rope_pairs']!r} rope pairs")
-    return perm
+def counts(ctx, s: dict) -> dict:
+    """The reference's counts the metric readers use, of its sizes ``s``."""
+    ref, planes = reference_module(ctx), ctx.conf["weight_planes"]
+    return {"matmul_params": ref.matmul_params(s),
+            "plane_elements": ref.plane_elements(
+                s, planes["leaves"], planes["min_elements"]),
+            "attention_layers": ref.attention_layers(s)}
 
 
-def to_program(conf: dict, cfg, w: dict) -> dict:
-    """Published-layout weights -> the program's parameter tree (a
-    checkpoint conversion: layers split into the program's precision
-    groups, rotary head dims reordered to the program's pairing)."""
-    ref = reference_module(conf)
-    s = ref.sizes(conf)
-    perm = rope_permutation(s)
-    hd = s["head_dim"]
-
-    def heads(x):  # reorder each head's dims along the last axis
-        sh = x.shape
-        return x.reshape(*sh[:-1], sh[-1] // hd, hd)[..., perm].reshape(sh)
-
-    lpg = cfg.layers_per_group
-    groups = []
-    for g in range(cfg.num_groups):
-        sl = slice(g * lpg, (g + 1) * lpg)
-        attn = {"wq": heads(w["wq"][sl]), "wk": heads(w["wk"][sl]),
-                "wv": w["wv"][sl], "wo": w["wo"][sl], "ln": w["ln1"][sl]}
-        if s["qkv_bias"]:
-            attn.update(bq=heads(w["bq"][sl]), bk=heads(w["bk"][sl]),
-                        bv=w["bv"][sl])
-        if s["qk_norm"]:
-            attn.update(q_norm=w["q_norm"][sl][..., perm],
-                        k_norm=w["k_norm"][sl][..., perm])
-        mix = {"ln": w["ln2"][sl], "w_gate": w["w_gate"][sl],
-               "w_up": w["w_up"][sl], "w_down": w["w_down"][sl]}
-        groups.append({"p0": {"attn": attn, "mix": mix}})
-    return {"groups": groups, "embed": w["embed"], "head": w["head"],
-            "final_norm": w["final_norm"]}
-
-
-def make_engine(ctx, cfg):
+def make_engine(ctx, cfg, s: dict):
     """Weights on the device from the seed, in one jitted call, and the
     engine over them."""
     import jax
@@ -134,16 +87,17 @@ def make_engine(ctx, cfg):
     from repro.plan import PrecisionPlan
     from repro.serve.engine import ServeEngine
 
-    ref = reference_module(ctx.conf)
+    ref, conv = reference_module(ctx), converter(ctx)
     shapes, metas = param_shapes(cfg)
-    build = jax.jit(lambda k: to_program(ctx.conf, cfg,
-                                         ref.make_weights(ctx.conf, k)))
+    build = jax.jit(lambda k: conv.to_program(
+        ctx.conf, s, cfg, ref.make_weights(ctx.conf, k)))
     params = build(ref.weight_key(ctx.seed))
     got = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), params)
     want = jax.tree_util.tree_map(lambda a: (a.shape, a.dtype), shapes)
     if got != want:
-        raise ValueError("converted weights do not match the program's "
-                         "parameter tree")
+        raise ValueError(f"the weights converted by {ctx.conf['name']}'s "
+                         f"{ctx.conf['program']['convert']!r} do not match "
+                         "the program's parameter tree")
     spec_tree = build_spec_tree(shapes, metas, SINGLE)
     storage = tree_to_storage(params, spec_tree, SINGLE)
     del params
@@ -258,8 +212,9 @@ class Window:
 
 def setup(ctx):
     """(program config, engine) with every program of the mix warm."""
-    cfg = program_config(ctx.conf)
-    engine = make_engine(ctx, cfg)
+    s = reference_module(ctx).sizes(ctx.conf)
+    cfg = converter(ctx).program_config(ctx.conf, s)
+    engine = make_engine(ctx, cfg, s)
     warm_up(ctx, engine, cfg)
     return cfg, engine
 
@@ -381,6 +336,7 @@ def measure(ctx, engine, cfg) -> Window:
             if inflight:
                 tick(clock)
 
+    sizes = reference_module(ctx).sizes(ctx.conf)
     emitted = [t for f in flights.values() for t in f.times if w0 <= t <= w1]
     itl = []
     for f in flights.values():
@@ -400,7 +356,8 @@ def measure(ctx, engine, cfg) -> Window:
         "decode_tokens": sum(tick_active),
         "admit_tokens": admit_tokens,
         "kv_itemsize": np.dtype(engine.plan.compute_dtype).itemsize,
-        "sizes": reference_module(ctx.conf).sizes(ctx.conf),
+        "sizes": sizes,
+        "counts": counts(ctx, sizes),
         "conf": ctx.conf,
         "mix": mix,
         "traced_calls": tracer.calls if tracer is not None else None,
@@ -490,7 +447,7 @@ def served_gaps(ctx, finished: list):
     ``Reference.served_gaps``)."""
     import jax
 
-    ref = reference_module(ctx.conf)
+    ref = reference_module(ctx)
     p = dict(ctx.mix["plan"], **ctx.plan_overrides)
     weights = jax.jit(lambda k: ref.as_served(
         ref.make_weights(ctx.conf, k), ctx.conf["weight_planes"],

@@ -31,6 +31,7 @@ class Context:
     root: pathlib.Path    # the checkout; traces are written under it
     spans: Spans
     t_start: float        # perf_counter at process start
+    bench_dir: pathlib.Path = registry.BENCH  # where the items were found
     plan_overrides: dict = dataclasses.field(default_factory=dict)
 
 
@@ -80,7 +81,7 @@ def make_context(name: str, *, seed: int, seconds: float, trace: bool,
     ctx = Context(
         cell=cell, conf=conf, mix=mix, check=check, seed=seed,
         seconds=seconds, trace=trace, root=root,
-        spans=Spans(annotate=trace), t_start=t_start,
+        spans=Spans(annotate=trace), t_start=t_start, bench_dir=bench_dir,
         plan_overrides=dict(plan_overrides or {}),
     )
     return ctx, driver

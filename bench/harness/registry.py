@@ -2,13 +2,55 @@
 driver, correctness limits and metric readers.
 
     bench/configs/<config>.json     sizes of one model as it is run
+    bench/configs/<reference>.py    plain reference of an architecture
+    bench/configs/<converter>.py    its weights into the program's tree
     bench/traffic/<mix>.json        parameters of one traffic mix or job
     bench/drivers/<driver>.py       one entry point of the program
     bench/checks/<cell>.json        the limits `correct` is judged by
     bench/metrics/<metric>.py       one metric's reduction
 
-A later cell or metric is added by adding files of these kinds and
-entries in BENCHMARK.json; nothing here changes.
+A configuration file names its reference under ``reference.module`` and
+its converter under ``program.convert``. What each exports:
+
+- a reference (imports nothing of the program): ``sizes(conf)``, the
+  sizes by its own names (a model that runs the program's attention
+  kernels gives ``heads``, ``kv_heads`` and ``head_dim``);
+  ``weight_key(seed)``; ``make_weights(conf, key_data)``, published-
+  layout weights, traceable; ``as_served(w, planes, keep_bytes,
+  rounding)``; ``Reference(conf)`` with ``.block`` and
+  ``.served_gaps(w, prompt, served, pad_to=...)``; and the counts the
+  metric readers use, of its sizes ``s``: ``matmul_params(s)``
+  (parameters one token multiplies through: embedding out, head in,
+  only the experts a token is routed to), ``plane_elements(s, leaves,
+  min_elements)`` (elements of the leaves kept as byte planes: those
+  listed in ``weight_planes`` with at least ``min_elements`` a layer)
+  and ``attention_layers(s)`` (layers that run the paged and flash
+  attention kernels);
+- a converter (may import the program): ``program_config(conf, s)``,
+  the program's ModelConfig, and ``to_program(conf, s, cfg, w)``, the
+  reference's weights as the program's parameter tree, traceable. The
+  driver passes both the reference's ``sizes(conf)``.
+
+A reference or converter may build on another in its directory through
+:func:`beside`. A later configuration of the dense decoder, or of
+another architecture that the serving engine runs, is added as files
+of these kinds and entries in BENCHMARK.json, and so is a later cell or
+metric; the drivers, this harness and the readers stay as they are,
+within two limits of the program that the serving driver cannot see
+past:
+
+- the warm-up prefills one prompt per page-size bucket of the mix's
+  lengths, and the engine prefills expert and recurrent models at their
+  exact length, so a mix for such a model uses prompt lengths that are
+  page multiples, or it compiles inside the window; this holds until
+  the engine tells its caller which lengths it compiles;
+- the engine's expert dispatch drops tokens past its capacity (at
+  least 8 per expert) and a plain reference drops none, so an expert
+  cell keeps slots x top_k <= 8 and prompts short enough that no
+  expert is offered more than its capacity, or waits for dropless
+  routing in the program.
+
+A new entry point of the program brings a driver of its own.
 """
 from __future__ import annotations
 
@@ -51,6 +93,14 @@ def module(kind: str, name: str, *, bench_dir: pathlib.Path = BENCH):
     sys.modules[spec.name] = mod  # dataclasses look their module up
     spec.loader.exec_module(mod)
     return mod
+
+
+def beside(path: str, name: str):
+    """The module ``<name>.py`` in the directory of the file ``path``:
+    how a converter finds its reference, and a reference another it
+    builds on, in whichever copy of ``bench/`` they were loaded from."""
+    here = pathlib.Path(path).resolve().parent
+    return module(here.name, name, bench_dir=here.parent)
 
 
 def cell(bench: dict, name: str) -> dict:

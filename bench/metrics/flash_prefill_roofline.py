@@ -1,19 +1,20 @@
 """flash_prefill_roofline: the flash prefill kernel's least time over
 its device time in the traced window.
 
-Per admitted prompt of S tokens and per layer, causal attention takes
-4 x H x head_dim x S(S+1)/2 operations (scores and weighted sum over
-the lower triangle) and moves q, k, v and the output once:
-(2 H + 2 KV) x S x head_dim x itemsize bytes. The least time is the
+Per admitted prompt of S tokens and per attention layer (the
+reference's ``attention_layers``, in ``record["counts"]``), causal
+attention takes 4 x H x head_dim x S(S+1)/2 operations (scores and
+weighted sum over the lower triangle) and moves q, k, v and the output
+once: (2 H + 2 KV) x S x head_dim x itemsize bytes. The least time is the
 larger of operations / bf16 peak and bytes / HBM bandwidth."""
 
 KERNEL = "flash_prefill"
 
 
-def work(s: dict, S: int, itemsize: int):
-    ops = 4 * s["heads"] * s["head_dim"] * S * (S + 1) // 2 * s["layers"]
+def work(s: dict, layers: int, S: int, itemsize: int):
+    ops = 4 * s["heads"] * s["head_dim"] * S * (S + 1) // 2 * layers
     nbytes = ((2 * s["heads"] + 2 * s["kv_heads"]) * S * s["head_dim"]
-              * itemsize * s["layers"])
+              * itemsize * layers)
     return ops, nbytes
 
 
@@ -24,9 +25,10 @@ def read(rec):
     t = red.kernel_s(KERNEL)
     if t <= 0:
         return None
+    layers = rec["counts"]["attention_layers"]
     ops = nbytes = 0
     for S in calls["admits"]:
-        o, b = work(rec["sizes"], S, rec["kv_itemsize"])
+        o, b = work(rec["sizes"], layers, S, rec["kv_itemsize"])
         ops, nbytes = ops + o, nbytes + b
     t_ops = ops / peaks["bf16_flops_per_s"]
     t_mem = nbytes / peaks["hbm_bytes_per_s"]

@@ -1,5 +1,6 @@
 """mfu.decode: the decode step's share of the chip's peak: 2 x matmul
-parameters per token (embedding excluded, head included) x decoded
+parameters per token (the reference's ``matmul_params``: embedding
+excluded, head included, only the experts a token is routed to) x decoded
 tokens, over the summed ``decode_tick`` time (``counts.step_mfu``)."""
 from bench.harness.counts import step_mfu
 

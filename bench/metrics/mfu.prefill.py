@@ -1,5 +1,6 @@
 """mfu.prefill: admission's share of the chip's peak: 2 x matmul
-parameters per prompt token (embedding excluded, head included; the
+parameters per prompt token (the reference's ``matmul_params``:
+embedding excluded, head included, only the routed experts; the
 attention's own operations left out) x prompt tokens admitted, over the
 summed ``admit`` time (``counts.step_mfu``)."""
 from bench.harness.counts import step_mfu

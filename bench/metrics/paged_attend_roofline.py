@@ -1,20 +1,23 @@
 """paged_attend_roofline: the paged decode-attention kernel's least
 time over its device time in the traced window.
 
-Per decode tick and layer, each active request of context c reads its
-keys and values once, 2 x c x KV x head_dim x itemsize bytes, and takes
-4 x c x H x head_dim operations (scores and weighted sum). The least
+Per decode tick and attention layer (the reference's
+``attention_layers``, in ``record["counts"]``), each active request of
+context c reads its keys and values once, 2 x c x KV x head_dim x
+itemsize bytes, and takes 4 x c x H x head_dim operations (scores and
+weighted sum). The least
 time is the larger of bytes / HBM bandwidth and operations / bf16 peak;
 ``bound`` says which."""
 
 KERNEL = "paged_attend"
 
 
-def work(s: dict, contexts, itemsize: int):
-    """(operations, bytes) of one tick over requests of these contexts."""
+def work(s: dict, layers: int, contexts, itemsize: int):
+    """(operations, bytes) of one tick over requests of these contexts,
+    in ``layers`` attention layers."""
     c = sum(contexts)
-    ops = 4 * c * s["heads"] * s["head_dim"] * s["layers"]
-    nbytes = 2 * c * s["kv_heads"] * s["head_dim"] * itemsize * s["layers"]
+    ops = 4 * c * s["heads"] * s["head_dim"] * layers
+    nbytes = 2 * c * s["kv_heads"] * s["head_dim"] * itemsize * layers
     return ops, nbytes
 
 
@@ -25,9 +28,10 @@ def read(rec):
     t = red.kernel_s(KERNEL)
     if t <= 0:
         return None
+    layers = rec["counts"]["attention_layers"]
     ops = nbytes = 0
     for ctxs in calls["ticks"]:
-        o, b = work(rec["sizes"], ctxs, rec["kv_itemsize"])
+        o, b = work(rec["sizes"], layers, ctxs, rec["kv_itemsize"])
         ops, nbytes = ops + o, nbytes + b
     t_ops = ops / peaks["bf16_flops_per_s"]
     t_mem = nbytes / peaks["hbm_bytes_per_s"]

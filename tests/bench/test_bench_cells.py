@@ -15,6 +15,7 @@ ROOT = HERE.parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src"), str(HERE)]
 
 import bench_tiny  # noqa: E402
+from bench.harness import registry  # noqa: E402
 from bench.harness.cell import run_cell  # noqa: E402
 
 SEED = 2**31 + 11
@@ -96,3 +97,66 @@ def test_fault_token_altered_where_produced(tmp_path, monkeypatch):
                         lambda planes: (np.asarray(unpack(planes)) + 1) % vocab)
     result, _ = _run(tmp_path, bench_tiny.QWEN_LIKE)
     assert not result["correct"], result["checks"]
+
+
+# the conversion as the serving driver held it before it moved to
+# bench/configs/decoder_program.py, kept here to pin the move
+def _parent_rope_permutation(s):
+    hd, r = s["head_dim"], s["rotary_dim"]
+    perm = np.arange(hd)
+    if s["rope_pairs"] == "half":
+        i = np.arange(r // 2)
+        perm[0:r:2] = i
+        perm[1:r:2] = i + r // 2
+    elif s["rope_pairs"] != "interleaved":
+        raise ValueError(f"no mapping from {s['rope_pairs']!r} rope pairs")
+    return perm
+
+
+def _parent_to_program(s, cfg, w):
+    perm = _parent_rope_permutation(s)
+    hd = s["head_dim"]
+
+    def heads(x):
+        sh = x.shape
+        return x.reshape(*sh[:-1], sh[-1] // hd, hd)[..., perm].reshape(sh)
+
+    lpg = cfg.layers_per_group
+    groups = []
+    for g in range(cfg.num_groups):
+        sl = slice(g * lpg, (g + 1) * lpg)
+        attn = {"wq": heads(w["wq"][sl]), "wk": heads(w["wk"][sl]),
+                "wv": w["wv"][sl], "wo": w["wo"][sl], "ln": w["ln1"][sl]}
+        if s["qkv_bias"]:
+            attn.update(bq=heads(w["bq"][sl]), bk=heads(w["bk"][sl]),
+                        bv=w["bv"][sl])
+        if s["qk_norm"]:
+            attn.update(q_norm=w["q_norm"][sl][..., perm],
+                        k_norm=w["k_norm"][sl][..., perm])
+        mix = {"ln": w["ln2"][sl], "w_gate": w["w_gate"][sl],
+               "w_up": w["w_up"][sl], "w_down": w["w_down"][sl]}
+        groups.append({"p0": {"attn": attn, "mix": mix}})
+    return {"groups": groups, "embed": w["embed"], "head": w["head"],
+            "final_norm": w["final_norm"]}
+
+
+@pytest.mark.parametrize("config", CONFIGS, ids=IDS)
+def test_converter_gives_the_parent_tree_bit_for_bit(config):
+    import jax
+
+    ref = registry.module("configs", "decoder")
+    conv = registry.module("configs", config["program"]["convert"])
+    s = ref.sizes(config)
+    cfg = conv.program_config(config, s)
+    key = ref.weight_key(SEED)
+    new = jax.jit(lambda k: conv.to_program(
+        config, s, cfg, ref.make_weights(config, k)))(key)
+    old = jax.jit(lambda k: _parent_to_program(
+        s, cfg, ref.make_weights(config, k)))(key)
+    new_leaves, new_def = jax.tree_util.tree_flatten(new)
+    old_leaves, old_def = jax.tree_util.tree_flatten(old)
+    assert new_def == old_def
+    for a, b in zip(new_leaves, old_leaves):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        assert np.array_equal(np.asarray(a).view(np.uint32),
+                              np.asarray(b).view(np.uint32))
